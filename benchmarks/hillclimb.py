@@ -110,7 +110,7 @@ def measure(arch: str, shape: str, overrides: dict, multi_pod=False) -> dict:
     chips = 512 if multi_pod else 256
 
     t0 = time.time()
-    bundle = build_bundle(harness, cell, mesh, multi_pod=multi_pod)
+    bundle = build_bundle(harness, cell, mesh)
     compiled = lower_bundle(bundle, mesh).compile()
     mem = compiled.memory_analysis()
     peak_gb = (
@@ -118,7 +118,7 @@ def measure(arch: str, shape: str, overrides: dict, multi_pod=False) -> dict:
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     ) / 1e9
 
-    metrics = extrapolated_metrics(harness, cell, mesh, multi_pod)
+    metrics = extrapolated_metrics(harness, cell, mesh)
     from repro.launch.dryrun import analytic_model_flops
 
     roof = Roofline(

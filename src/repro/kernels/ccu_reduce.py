@@ -50,7 +50,7 @@ def ccu_reduce(
     scales: jax.Array | None = None,   # (P,) dequant scales (int8 ingestion)
     *,
     block_n: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Deterministic-order peer reduction -> (N,) fp32."""
     P, N = bufs.shape

@@ -101,7 +101,7 @@ def flash_attention(
     sm_scale: float | None = None,
     block_q: int = DEFAULT_BQ,
     block_k: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, K, G, Sq, D = q.shape
     Sk = k.shape[2]

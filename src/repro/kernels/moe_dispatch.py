@@ -48,7 +48,7 @@ def moe_dispatch(
     x: jax.Array,       # (T, D)
     *,
     block_t: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns expert inputs (E, C, D)."""
     T, E, C = disp.shape
@@ -77,7 +77,7 @@ def moe_gather_matmul(
     x: jax.Array,       # (T, D)
     w: jax.Array,       # (E, D, F)
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Dispatch + expert matmul: (E, C, F)."""
     ein = moe_dispatch(disp, x, interpret=interpret)        # (E, C, D)

@@ -97,7 +97,7 @@ def rwkv6_scan(
     *,
     chunk: int = 128,
     tile: int = 16,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     B, S, H, N = r.shape
     Q = min(chunk, S)

@@ -69,7 +69,7 @@ def ssd_scan(
     Cm: jax.Array,      # (B, S, N)
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     B, S, H, P = xh.shape
     N = Bm.shape[-1]

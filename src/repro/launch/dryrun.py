@@ -70,7 +70,7 @@ def _cost_dict(compiled) -> dict:
     return dict(cost) if cost else {}
 
 
-def _probe_metrics(harness, cell, mesh, multi_pod) -> dict:
+def _probe_metrics(harness, cell, mesh) -> dict:
     """Compile one UNROLLED probe and return its per-device counters.
 
     XLA's cost analysis visits while-loop (lax.scan) bodies once, so the
@@ -80,7 +80,7 @@ def _probe_metrics(harness, cell, mesh, multi_pod) -> dict:
     chunks for SSM scans; attention's S^2 captured exactly at full S or via
     a quadratic fit for the hybrid's shared block).
     """
-    bundle = build_bundle(harness, cell, mesh, multi_pod=multi_pod)
+    bundle = build_bundle(harness, cell, mesh)
     compiled = lower_bundle(bundle, mesh).compile()
     cost = _cost_dict(compiled)
     coll = collective_stats(compiled.as_text())
@@ -99,7 +99,7 @@ def _probe_cell(cell, seq_len):
     return dataclasses.replace(cell, seq_len=seq_len)
 
 
-def extrapolated_metrics(harness, cell, mesh, multi_pod) -> dict:
+def extrapolated_metrics(harness, cell, mesh) -> dict:
     """Per-device (flops, hbm bytes, wire bytes) at the FULL (L, S)."""
     fam = harness.family
     keys = ("flops", "hbm", "wire")
@@ -107,7 +107,7 @@ def extrapolated_metrics(harness, cell, mesh, multi_pod) -> dict:
     def probe(L, S=None, **extra):
         h = harness.clone(n_layers=L, unroll=True, **extra)
         c = cell if S is None else _probe_cell(cell, S)
-        return _probe_metrics(h, c, mesh, multi_pod)
+        return _probe_metrics(h, c, mesh)
 
     if fam in ("dense", "moe", "vlm", "audio"):
         L_full = harness.cfg.n_layers
@@ -211,7 +211,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, probes: bool = True) -> dic
 
     # ---- official artifact: the scanned full-depth program ----------------
     t0 = time.time()
-    bundle = build_bundle(harness, cell, mesh, multi_pod=multi_pod)
+    bundle = build_bundle(harness, cell, mesh)
     lowered = lower_bundle(bundle, mesh)
     rec["lower_s"] = round(time.time() - t0, 1)
     t1 = time.time()
@@ -233,7 +233,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, probes: bool = True) -> dic
     # ---- cost counters: probe-extrapolated (see _probe_metrics docstring) -
     t2 = time.time()
     if probes:
-        metrics = extrapolated_metrics(harness, cell, mesh, multi_pod)
+        metrics = extrapolated_metrics(harness, cell, mesh)
     else:
         cost = _cost_dict(compiled)
         coll = collective_stats(compiled.as_text())

@@ -3,8 +3,9 @@ decode-serving simulator.
 
 Two layers share this module:
 
-* ``main()`` — the executable serving loop over the real model harness
-  (prefill + KV-cache decode with sampled tokens)::
+* ``serve()`` — the serving loop over the real model harness (prefill +
+  KV-cache decode with sampled tokens), called in-process by
+  ``chip_smoke.py``; ``main()`` is its command line::
 
       PYTHONPATH=src python -m repro.launch.serve --arch granite-8b \\
           --batch 4 --prompt-len 32 --gen 16        # --no-smoke for full
@@ -25,8 +26,9 @@ Two layers share this module:
   narrower TP x wider DP sharding.
 
 Everything simulator-side is importable without jax (the model-harness
-imports are deferred into ``main``), so benchmarks and planners can load
-it in environments where the accelerator stack is absent.
+imports are deferred into the functions that run the model), so
+benchmarks and planners can load it in environments where the
+accelerator stack is absent.
 """
 
 from __future__ import annotations
@@ -310,13 +312,111 @@ def rack_perf_model(cache_dir: "str | None" = "auto"):
 # ---------------------------------------------------------------------------
 
 
+def serve(harness, params, prompts, *, gen: int, temperature: float = 0.0,
+          seed: int = 0) -> dict:
+    """Prefill ``prompts`` (B, S) int32, then decode ``gen`` tokens with the
+    KV cache, on the default device.
+
+    The cache holds ``S + gen + 8`` positions.  Each program is compiled
+    ahead of its first call, so compile time and run time are reported
+    apart; every clock stops after ``block_until_ready``.  Returns the
+    generated ids (B, gen), the prefill's last-position logits, the first
+    decode step's logits (None when ``gen == 1``) and ``timings`` in s.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.api import ShapeCell
+    from repro.models.layers import Runtime
+    from repro.models.param import tree_init
+
+    cfg = harness.cfg
+    batch, prompt_len = prompts.shape
+    rt = Runtime(rules=None)
+    # independent streams for serve state and sampling
+    state_key, key = jax.random.split(jax.random.PRNGKey(seed))
+    max_len = prompt_len + gen + 8
+    cell = ShapeCell("serve", "decode", max_len, batch)
+    state = tree_init(harness.serve_state_specs(cell), state_key)
+    vocab = cfg.vocab_size
+
+    args = (params, state, prompts)
+    if harness.family == "audio":
+        frames = jnp.zeros((batch, cfg.n_frames, cfg.d_model), jnp.bfloat16)
+        args = (params, state, frames, prompts)
+    tok_spec = jax.ShapeDtypeStruct((batch, 1), jnp.int32)
+    pos_spec = jax.ShapeDtypeStruct((), jnp.int32)
+
+    t0 = time.perf_counter()
+    prefill = jax.jit(harness.prefill(rt)).lower(*args).compile()
+    t1 = time.perf_counter()
+    decode = jax.jit(harness.decode(rt)).lower(
+        params, state, tok_spec, pos_spec).compile()
+    t2 = time.perf_counter()
+
+    prefill_logits, state = jax.block_until_ready(prefill(*args))
+    t3 = time.perf_counter()
+
+    def sample(logits, key):
+        lg = logits[:, -1, :vocab].astype(jnp.float32)
+        if temperature <= 0:
+            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        return jax.random.categorical(key, lg / temperature).astype(jnp.int32)
+
+    key, sub = jax.random.split(key)
+    tok = sample(prefill_logits, sub)
+    out_tokens = [tok]
+    decode_logits = None
+    t4 = time.perf_counter()
+    for i in range(gen - 1):
+        pos = jnp.asarray(prompt_len + i, jnp.int32)
+        logits, state = decode(params, state, tok[:, None], pos)
+        if i == 0:
+            decode_logits = logits
+        key, sub = jax.random.split(key)
+        tok = sample(logits, sub)
+        out_tokens.append(tok)
+    jax.block_until_ready((tok, state))
+    t5 = time.perf_counter()
+
+    return {
+        "tokens": np.stack([np.asarray(t) for t in out_tokens], axis=1),
+        "prefill_logits": prefill_logits,
+        "decode_logits": decode_logits,
+        "max_len": max_len,
+        "timings": {
+            "prefill_compile_s": t1 - t0,
+            "decode_compile_s": t2 - t1,
+            "prefill_s": t3 - t2,
+            "decode_s_per_token": (t5 - t4) / max(gen - 1, 1),
+        },
+    }
+
+
+def prefill_reference_logits(harness, params, prompts, next_tokens, max_len: int):
+    """Last-position logits of a prefill over ``prompts`` + ``next_tokens``
+    (B,) into a fresh ``max_len`` cache: what ``serve()``'s first decode
+    step, fed ``next_tokens`` at position S, has to reproduce."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.api import ShapeCell
+    from repro.models.layers import Runtime
+    from repro.models.param import tree_init
+
+    cell = ShapeCell("serve", "decode", max_len, prompts.shape[0])
+    state = tree_init(harness.serve_state_specs(cell), jax.random.PRNGKey(0))
+    tokens = jnp.concatenate([prompts, next_tokens[:, None]], axis=1)
+    logits, _ = jax.jit(harness.prefill(Runtime(rules=None)))(params, state, tokens)
+    return logits
+
+
 def main():
     import jax
     import jax.numpy as jnp
 
     from repro.configs import load
-    from repro.models.api import ShapeCell
-    from repro.models.layers import Runtime
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.param import tree_init
 
     ap = argparse.ArgumentParser()
@@ -332,59 +432,24 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     harness = load(args.arch, smoke=args.smoke)
-    cfg = harness.cfg
-    rt = Runtime(rules=None)
-    # independent streams for params, serve state and sampling — reusing
-    # one key would correlate weight init with KV-state init and make the
-    # first sampled token share the params' randomness
-    key = jax.random.PRNGKey(args.seed)
-    key, params_key, state_key = jax.random.split(key, 3)
-    params = tree_init(harness.param_specs(), params_key, dtype=jnp.bfloat16)
-
-    max_len = args.prompt_len + args.gen + 8
-    cell = ShapeCell("serve", "decode", max_len, args.batch)
-    state = tree_init(harness.serve_state_specs(cell), state_key)
-
-    prefill = jax.jit(harness.prefill(rt))
-    decode = jax.jit(harness.decode(rt))
-
+    vocab = harness.cfg.vocab_size
+    # params and prompts from their own seeds, apart from serve()'s streams
+    params = tree_init(harness.param_specs(),
+                       jax.random.PRNGKey(args.seed + 1), dtype=jnp.bfloat16)
     rng = np.random.default_rng(args.seed)
-    vocab = cfg.vocab_size
     prompts = jnp.asarray(
         rng.integers(0, vocab, size=(args.batch, args.prompt_len), dtype=np.int32)
     )
+    out = serve(harness, params, prompts, gen=args.gen,
+                temperature=args.temperature, seed=args.seed)
 
-    t0 = time.time()
-    if harness.family == "audio":
-        frames = jnp.zeros((args.batch, cfg.n_frames, cfg.d_model), jnp.bfloat16)
-        logits, state = prefill(params, state, frames, prompts)
-    else:
-        logits, state = prefill(params, state, prompts)
-    t_prefill = time.time() - t0
-
-    def sample(logits, key):
-        lg = logits[:, -1, :vocab].astype(jnp.float32)
-        if args.temperature <= 0:
-            return jnp.argmax(lg, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(key, lg / args.temperature).astype(jnp.int32)
-
-    key, sub = jax.random.split(key)
-    tok = sample(logits, sub)
-    out_tokens = [tok]
-    t1 = time.time()
-    for i in range(args.gen - 1):
-        pos = jnp.asarray(args.prompt_len + i, jnp.int32)
-        logits, state = decode(params, state, tok[:, None], pos)
-        key, sub = jax.random.split(key)
-        tok = sample(logits, sub)
-        out_tokens.append(tok)
-    t_decode = time.time() - t1
-
-    gen = np.stack([np.asarray(t) for t in out_tokens], axis=1)
+    gen, t = out["tokens"], out["timings"]
     print(f"[serve] arch={args.arch} batch={args.batch} "
-          f"prefill={t_prefill*1e3:.0f}ms "
-          f"decode={t_decode/max(args.gen-1,1)*1e3:.1f}ms/tok")
+          f"prefill={t['prefill_s']*1e3:.0f}ms "
+          f"decode={t['decode_s_per_token']*1e3:.1f}ms/tok "
+          f"compile={t['prefill_compile_s'] + t['decode_compile_s']:.1f}s")
     print(f"[serve] generated token ids (first row): {gen[0][:16].tolist()}")
     assert gen.shape == (args.batch, args.gen)
     assert np.all(gen >= 0) and np.all(gen < vocab)

@@ -31,7 +31,6 @@ class Runtime:
     """Sharding context threaded through every layer."""
 
     rules: ShardingRules | None = None
-    interpret_kernels: bool = True    # pallas interpret mode (CPU container)
     use_kernels: bool = False         # route hot-spots through Pallas ops
 
     def shard(self, x: jax.Array, *logical: str | None) -> jax.Array:

@@ -68,15 +68,23 @@ def _init_leaf(spec: ParamSpec, key: jax.Array) -> jax.Array:
 
 
 def tree_init(spec_tree: PyTree, key: jax.Array, dtype=None) -> PyTree:
+    """Materialize a spec tree.  One jitted program makes every leaf, so
+    a leaf cast to ``dtype`` is never held in float32 on the device, and
+    a full-width model costs one compile instead of several per leaf."""
     leaves, treedef = jax.tree.flatten(spec_tree, is_leaf=is_spec)
-    keys = jax.random.split(key, len(leaves))
-    out = []
-    for spec, k in zip(leaves, keys):
-        x = _init_leaf(spec, k)
-        if dtype is not None:
-            x = x.astype(dtype)
-        out.append(x)
-    return jax.tree.unflatten(treedef, out)
+
+    @jax.jit
+    def make(key):
+        keys = jax.random.split(key, len(leaves))
+        out = []
+        for spec, k in zip(leaves, keys):
+            x = _init_leaf(spec, k)
+            if dtype is not None:
+                x = x.astype(dtype)
+            out.append(x)
+        return out
+
+    return jax.tree.unflatten(treedef, make(key))
 
 
 def tree_abstract(spec_tree: PyTree, dtype=None) -> PyTree:

@@ -23,7 +23,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -46,10 +45,10 @@ def hierarchical_allreduce(mesh: Mesh, fast_axis: str, slow_axes: tuple[str, ...
         x = jax.lax.all_gather(x, fast_axis, axis=0, tiled=True)
         return x
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=P(), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -59,7 +58,7 @@ def flat_allreduce(mesh: Mesh, axes: tuple[str, ...]):
     def inner(x):
         return jax.lax.psum(x, axes)
 
-    return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(), check_rep=False)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
 
 
 def multipath_split(mesh: Mesh, axis_a: str, axis_b: str):
@@ -76,8 +75,8 @@ def multipath_split(mesh: Mesh, axis_a: str, axis_b: str):
         b = jax.lax.all_gather(x[h:], axis_b, axis=0, tiled=True)
         return a, b
 
-    return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=(P(), P()),
-                     check_rep=False)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=(P(), P()),
+                         check_vma=False)
 
 
 def hierarchical_all_to_all(mesh: Mesh, intra_axis: str, inter_axis: str):
@@ -97,5 +96,5 @@ def hierarchical_all_to_all(mesh: Mesh, intra_axis: str, inter_axis: str):
         x = jax.lax.all_to_all(x, inter_axis, split_axis=0, concat_axis=0, tiled=False)
         return x.reshape(n_inter * n_intra, *x.shape[2:])
 
-    return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)

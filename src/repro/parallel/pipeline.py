@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -35,7 +34,9 @@ def pipelined_forward(
     GPipe schedule: T = n_micro + n_stages - 1 ticks; at each tick every
     stage processes its resident microbatch then ppermutes the activation
     to the next stage.  Returns fn(stage_params, x) -> y where x and y are
-    (n_micro, mb, ...) batches living on stage 0 / stage n-1 respectively.
+    (n_micro, mb, ...) batches; y is the last stage's output, broadcast to
+    every stage by one psum (only the last stage's buffer is non-zero), so
+    no caller has to slice the sharded stage dim.
     """
     n_stages = mesh.shape[stage_axis]
 
@@ -79,20 +80,15 @@ def pipelined_forward(
         (_, outputs), _ = jax.lax.scan(
             tick, (buf0, outs0), jnp.arange(n_ticks)
         )
-        return outputs[None]                  # (1, n_micro, mb, ...)
+        return jax.lax.psum(outputs, stage_axis)   # (n_micro, mb, ...)
 
-    mapped = shard_map(
+    return jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(stage_axis), P()),        # params staged; x replicated
-        out_specs=P(stage_axis),              # (n_stages, n_micro, mb, ...)
-        check_rep=False,
+        out_specs=P(),                        # the last stage's y, everywhere
+        check_vma=False,
     )
-
-    def fn(stage_params, x):
-        return mapped(stage_params, x)[-1]    # the LAST stage's collected y
-
-    return fn
 
 
 def stage_split(tree, n_stages: int):

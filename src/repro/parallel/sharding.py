@@ -10,6 +10,9 @@ The production mesh is ("data", "model") = (16, 16) per pod, plus a leading
   DP-class traffic: batch dim, ZeRO-1 optimizer shards, FSDP dims of the
   100B+ experts.
 
+The DP width is read from the mesh (``mesh.shape``), so the same rules
+serve the production mesh and a 2x2 mesh of one TPU host.
+
 ``ShardingRules.pspec`` drops an axis that is already used by an earlier
 tensor dim, so ONE rule set adapts between train (sp-sharded activations =
 FSDP-like weight gathers) and decode (sp off => ff/vocab dims take "model" =
@@ -80,14 +83,23 @@ def make_rules(
     return ShardingRules(rules=rules)
 
 
-def rules_for_cell(harness, cell, *, multi_pod: bool) -> ShardingRules:
-    """Pick the per-(arch x shape) rule set the dry-run/train/serve use."""
-    dp_size = 32 if multi_pod else 16
-    batch_ok = cell.global_batch % dp_size == 0 and cell.global_batch >= dp_size
+def _dp_axes(mesh_shape) -> tuple[str, ...]:
+    """The data-parallel axes present in a mesh, outermost first."""
+    return tuple(a for a in (POD_AXIS, DATA_AXIS) if a in mesh_shape)
+
+
+def dp_size(mesh_shape) -> int:
+    """Data-parallel width of a mesh: the product of its DP axes."""
+    return int(np.prod([mesh_shape[a] for a in _dp_axes(mesh_shape)]))
+
+
+def rules_for_cell(harness, cell, mesh_shape) -> ShardingRules:
+    """Pick the per-(arch x shape) rule set for a mesh (``mesh.shape``)."""
+    dp = dp_size(mesh_shape)
     return make_rules(
-        multi_pod=multi_pod,
+        multi_pod=POD_AXIS in mesh_shape,
         sp=cell.kind != "decode",
-        batch_shardable=batch_ok,
+        batch_shardable=cell.global_batch % dp == 0,
         moe_strategy=harness.moe_strategy,
     )
 
@@ -97,35 +109,33 @@ def rules_for_cell(harness, cell, *, multi_pod: bool) -> ShardingRules:
 # ---------------------------------------------------------------------------
 
 
-def zero1_pspec(spec: ParamSpec, rules: ShardingRules, dp_size: int) -> P:
+def zero1_pspec(spec: ParamSpec, rules: ShardingRules, mesh_shape) -> P:
     """Param pspec + the DP axes added on the first free, divisible dim.
 
     This is the ZeRO-1 partitioning of fp32 master/moment tensors: model-
-    sharded dims stay, and one replicated dim additionally shards over
-    ("pod","data").  Falls back to the plain param spec when nothing divides.
+    sharded dims stay, and one replicated dim additionally shards over the
+    mesh's DP axes ("pod", "data").  Falls back to the plain param spec
+    when nothing divides.
     """
     base = rules.pspec(spec.logical)
     entries = list(base) + [None] * (len(spec.shape) - len(base))
     used = {a for e in entries if e for a in ((e,) if isinstance(e, str) else e)}
-    dp_axes = tuple(
-        a for a in ((POD_AXIS, DATA_AXIS) if dp_size > 16 else (DATA_AXIS,))
-        if a not in used
-    )
-    if not dp_axes:
+    free = tuple(a for a in _dp_axes(mesh_shape) if a not in used)
+    if not free:
         return base
-    dp_total = int(np.prod([dp_size // 16 if a == POD_AXIS else 16 for a in dp_axes]))
+    dp_total = int(np.prod([mesh_shape[a] for a in free]))
     # skip the scanned-layers dim (dim 0 when logical starts with "layers")
     start = 1 if spec.logical and spec.logical[0] == "layers" else 0
     for i in range(start, len(spec.shape)):
         if entries[i] is None and spec.shape[i] % dp_total == 0 and spec.shape[i] > 0:
-            entries[i] = dp_axes if len(dp_axes) > 1 else dp_axes[0]
+            entries[i] = free if len(free) > 1 else free[0]
             break
     while entries and entries[-1] is None:
         entries.pop()
     return P(*entries)
 
 
-def tree_zero1_pspecs(spec_tree, rules: ShardingRules, dp_size: int):
+def tree_zero1_pspecs(spec_tree, rules: ShardingRules, mesh_shape):
     return jax.tree.map(
-        lambda s: zero1_pspec(s, rules, dp_size), spec_tree, is_leaf=is_spec
+        lambda s: zero1_pspec(s, rules, mesh_shape), spec_tree, is_leaf=is_spec
     )

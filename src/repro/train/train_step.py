@@ -48,35 +48,14 @@ def _shardings(mesh: Mesh, pspec_tree):
     )
 
 
-def build_train_step(
-    harness: Harness,
-    cell: ShapeCell,
-    mesh: Mesh,
-    *,
-    multi_pod: bool = False,
-    opt_cfg: adamw.OptConfig | None = None,
-    compression: CompressionConfig | None = None,
-    rules: ShardingRules | None = None,
-) -> StepBundle:
-    opt_cfg = opt_cfg or adamw.OptConfig()
-    compression = compression or CompressionConfig()
-    rules = rules or rules_for_cell(harness, cell, multi_pod=multi_pod)
-    rt = Runtime(rules=rules)
-    loss_fn = harness.loss(rt)
-    dp_size = 32 if multi_pod else 16
-
-    param_specs = harness.param_specs()
-    opt_specs = adamw.opt_state_specs(param_specs)
-    input_specs = harness.train_input_specs(cell)
-
-    param_ps = tree_pspecs(param_specs, rules)
-    opt_ps = {
-        "master": tree_zero1_pspecs(param_specs, rules, dp_size),
-        "m": tree_zero1_pspecs(param_specs, rules, dp_size),
-        "v": tree_zero1_pspecs(param_specs, rules, dp_size),
-        "step": P(),
-    }
-    input_ps = tree_pspecs(input_specs, rules)
+def make_train_step(
+    loss_fn: Callable,
+    opt_cfg: adamw.OptConfig,
+    compression: CompressionConfig,
+) -> Callable:
+    """``(params, opt_state, batch) -> (params, opt_state, metrics)``: one
+    AdamW step on ``loss_fn``'s gradients.  The launcher jits it on one
+    device; ``build_train_step`` jits it with mesh shardings."""
 
     def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -84,6 +63,36 @@ def build_train_step(
         new_params, new_opt, metrics = adamw.apply(opt_cfg, params, grads, opt_state)
         metrics["loss"] = loss
         return new_params, new_opt, metrics
+
+    return train_step
+
+
+def build_train_step(
+    harness: Harness,
+    cell: ShapeCell,
+    mesh: Mesh,
+    *,
+    opt_cfg: adamw.OptConfig | None = None,
+    compression: CompressionConfig | None = None,
+    rules: ShardingRules | None = None,
+) -> StepBundle:
+    opt_cfg = opt_cfg or adamw.OptConfig()
+    compression = compression or CompressionConfig()
+    rules = rules or rules_for_cell(harness, cell, mesh.shape)
+    loss_fn = harness.loss(Runtime(rules=rules))
+
+    param_specs = harness.param_specs()
+    opt_specs = adamw.opt_state_specs(param_specs)
+    input_specs = harness.train_input_specs(cell)
+
+    param_ps = tree_pspecs(param_specs, rules)
+    opt_ps = {
+        "master": tree_zero1_pspecs(param_specs, rules, mesh.shape),
+        "m": tree_zero1_pspecs(param_specs, rules, mesh.shape),
+        "v": tree_zero1_pspecs(param_specs, rules, mesh.shape),
+        "step": P(),
+    }
+    input_ps = tree_pspecs(input_specs, rules)
 
     abstract = (
         tree_abstract(param_specs, dtype=jnp.bfloat16),
@@ -101,7 +110,7 @@ def build_train_step(
         None,
     )
     return StepBundle(
-        fn=train_step,
+        fn=make_train_step(loss_fn, opt_cfg, compression),
         in_shardings=in_sh,
         out_shardings=out_sh,
         abstract_args=abstract,
@@ -114,11 +123,10 @@ def build_serve_step(
     cell: ShapeCell,
     mesh: Mesh,
     *,
-    multi_pod: bool = False,
     rules: ShardingRules | None = None,
 ) -> StepBundle:
     """Prefill (cell.kind == 'prefill') or decode step bundle."""
-    rules = rules or rules_for_cell(harness, cell, multi_pod=multi_pod)
+    rules = rules or rules_for_cell(harness, cell, mesh.shape)
     rt = Runtime(rules=rules)
 
     param_specs = harness.param_specs()
@@ -158,10 +166,10 @@ def build_serve_step(
     )
 
 
-def build_bundle(harness, cell: ShapeCell, mesh, *, multi_pod: bool, **kw) -> StepBundle:
+def build_bundle(harness, cell: ShapeCell, mesh, **kw) -> StepBundle:
     if cell.kind == "train":
-        return build_train_step(harness, cell, mesh, multi_pod=multi_pod, **kw)
-    return build_serve_step(harness, cell, mesh, multi_pod=multi_pod)
+        return build_train_step(harness, cell, mesh, **kw)
+    return build_serve_step(harness, cell, mesh)
 
 
 def lower_bundle(bundle: StepBundle, mesh: Mesh):

@@ -41,13 +41,13 @@ class TestShardingRules:
     def test_zero1_adds_dp_axis_on_free_dim(self):
         rules = make_rules(multi_pod=False, sp=True)
         s = ParamSpec((4096, 1024), ("embed_in", "ff"))
-        ps = zero1_pspec(s, rules, dp_size=16)
+        ps = zero1_pspec(s, rules, {"data": 16, "model": 16})
         assert ps == P("data", "model")
 
     def test_zero1_skips_layer_dim(self):
         rules = make_rules(multi_pod=False, sp=True)
         s = ParamSpec((36, 4096, 1024), ("layers", "embed_in", "ff"))
-        ps = zero1_pspec(s, rules, dp_size=16)
+        ps = zero1_pspec(s, rules, {"data": 16, "model": 16})
         assert ps == P(None, "data", "model")
 
     @given(st.integers(1, 4), st.integers(1, 4))

@@ -1,12 +1,16 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles."""
 
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st  # hypothesis or skip-shim
 
-from repro.kernels import ops, ref
+from repro.kernels import (
+    ccu_reduce, flash_attention, moe_dispatch, ops, ref, rwkv6_scan, ssd_scan,
+)
 
 KEY = jax.random.PRNGKey(42)
 
@@ -185,3 +189,17 @@ class TestCCUReduce:
         o1 = np.asarray(ops.ccu_reduce(bufs))
         o2 = np.asarray(ops.ccu_reduce(bufs))
         assert (o1 == o2).all()
+
+
+@pytest.mark.parametrize("fn", [
+    flash_attention.flash_attention,
+    ssd_scan.ssd_scan,
+    rwkv6_scan.rwkv6_scan,
+    moe_dispatch.moe_dispatch,
+    moe_dispatch.moe_gather_matmul,
+    ccu_reduce.ccu_reduce,
+])
+def test_interpret_mode_has_no_default(fn):
+    """ops.py picks interpret mode by backend; a kernel never defaults to it."""
+    default = inspect.signature(fn).parameters["interpret"].default
+    assert default is inspect.Parameter.empty
