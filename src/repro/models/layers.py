@@ -258,30 +258,32 @@ def attention(
     G = N // K
 
     kv_src = kv_override if kv_override is not None else x
-    q = jnp.einsum("bsd,dp->bsp", x, p["wq"])
-    k = jnp.einsum("bsd,dp->bsp", kv_src, p["wk"])
-    v = jnp.einsum("bsd,dp->bsp", kv_src, p["wv"])
-    if "bq" in p:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(B, S, N, Dh)
-    k = k.reshape(B, kv_src.shape[1], K, Dh)
-    v = v.reshape(B, kv_src.shape[1], K, Dh)
-    q = rt.shard(q, "batch", "sp", None, None)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dp->bsp", x, p["wq"])
+        k = jnp.einsum("bsd,dp->bsp", kv_src, p["wk"])
+        v = jnp.einsum("bsd,dp->bsp", kv_src, p["wv"])
+        if "bq" in p:
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+        q = q.reshape(B, S, N, Dh)
+        k = k.reshape(B, kv_src.shape[1], K, Dh)
+        v = v.reshape(B, kv_src.shape[1], K, Dh)
+        q = rt.shard(q, "batch", "sp", None, None)
 
-    if cfg.rope_theta is not None and kv_override is None:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.rope_theta is not None and kv_override is None:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
 
     new_cache = None
     if kv_cache is not None:
         ck, cv = kv_cache
         if cache_pos is not None:
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, cache_pos, 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, cache_pos, 0, 0)
-            )
+            with jax.named_scope("kv_cache"):
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k.astype(ck.dtype), (0, cache_pos, 0, 0)
+                )
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v.astype(cv.dtype), (0, cache_pos, 0, 0)
+                )
         k, v = ck, cv
         new_cache = (ck, cv)
         k_pos = jnp.arange(k.shape[1])
@@ -301,28 +303,30 @@ def attention(
         and kv_override is None
         and (kv_cache is None or (S > 1 and S == k.shape[1]))
     )
-    if blocked_ok:
-        out = blocked_sdpa(
-            qg, k, v,
-            causal=cfg.causal, window=cfg.window, prefix_len=cfg.prefix_len,
-        )
-    else:
-        if kv_override is not None:
-            bias = None                                # cross-attn: full view
-        else:
-            # positions are the q tokens' GLOBAL positions, so the same mask
-            # covers train (full S), prefill (cache write at 0) and decode
-            # (single token at cache_pos)
-            bias = _mask_bias(
-                positions, k_pos, cfg.causal, cfg.window, cfg.prefix_len
+    with jax.named_scope("sdpa"):
+        if blocked_ok:
+            out = blocked_sdpa(
+                qg, k, v,
+                causal=cfg.causal, window=cfg.window, prefix_len=cfg.prefix_len,
             )
-        out = sdpa(qg, k, v, bias)
+        else:
+            if kv_override is not None:
+                bias = None                                # cross-attn: full view
+            else:
+                # positions are the q tokens' GLOBAL positions, so the same mask
+                # covers train (full S), prefill (cache write at 0) and decode
+                # (single token at cache_pos)
+                bias = _mask_bias(
+                    positions, k_pos, cfg.causal, cfg.window, cfg.prefix_len
+                )
+            out = sdpa(qg, k, v, bias)
     out = out.reshape(B, S, N * Dh)
     out = rt.shard(out, "batch", "sp", None)
-    y = jnp.einsum("bsp,pd->bsd", out, p["wo"])
-    if "bo" in p:
-        y = y + p["bo"]
-    y = rt.shard(y, "batch", "sp", None)
+    with jax.named_scope("attn_out"):
+        y = jnp.einsum("bsp,pd->bsd", out, p["wo"])
+        if "bo" in p:
+            y = y + p["bo"]
+        y = rt.shard(y, "batch", "sp", None)
     return y, new_cache
 
 
@@ -400,25 +404,28 @@ def embed_specs(vocab_padded: int, d_model: int) -> dict:
 
 
 def embed(rt: Runtime, p: dict, tokens: jax.Array) -> jax.Array:
-    x = jnp.take(p["tok"], tokens, axis=0)
-    return rt.shard(x, "batch", "sp", None)
+    with jax.named_scope("embed"):
+        x = jnp.take(p["tok"], tokens, axis=0)
+        return rt.shard(x, "batch", "sp", None)
 
 
 def unembed(rt: Runtime, p: dict, x: jax.Array) -> jax.Array:
-    logits = jnp.einsum("bsd,dv->bsv", x, p["unembed"])
-    return rt.shard(logits, "batch", "sp", "vocab")
+    with jax.named_scope("head"):
+        logits = jnp.einsum("bsd,dv->bsv", x, p["unembed"])
+        return rt.shard(logits, "batch", "sp", "vocab")
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array, vocab_real: int) -> jax.Array:
     """Mean NLL over (possibly vocab-sharded) logits; fused one-hot gold
     extraction so GSPMD never all-gathers the vocab dim; padded tail masked.
     """
-    lg = logits.astype(jnp.float32)
-    V = lg.shape[-1]
-    if vocab_real < V:
-        mask = jnp.arange(V) < vocab_real
-        lg = jnp.where(mask, lg, -1e9)
-    logz = jax.nn.logsumexp(lg, axis=-1)
-    onehot = jax.nn.one_hot(labels, V, dtype=lg.dtype)
-    gold = jnp.sum(lg * onehot, axis=-1)
-    return jnp.mean(logz - gold)
+    with jax.named_scope("loss"):
+        lg = logits.astype(jnp.float32)
+        V = lg.shape[-1]
+        if vocab_real < V:
+            mask = jnp.arange(V) < vocab_real
+            lg = jnp.where(mask, lg, -1e9)
+        logz = jax.nn.logsumexp(lg, axis=-1)
+        onehot = jax.nn.one_hot(labels, V, dtype=lg.dtype)
+        gold = jnp.sum(lg * onehot, axis=-1)
+        return jnp.mean(logz - gold)

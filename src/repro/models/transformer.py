@@ -84,7 +84,8 @@ def _norm_specs(cfg: LMConfig) -> Any:
 
 
 def _apply_norm(cfg: LMConfig, p: Any, x: jax.Array) -> jax.Array:
-    return L.rmsnorm(p, x) if cfg.norm == "rms" else L.layernorm(p, x)
+    with jax.named_scope("norm"):
+        return L.rmsnorm(p, x) if cfg.norm == "rms" else L.layernorm(p, x)
 
 
 def block_specs(cfg: LMConfig) -> dict:
@@ -132,12 +133,13 @@ def _block(
     x = x + a
     h = _apply_norm(cfg, p["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
-    if cfg.moe is not None:
-        m, aux = moe_apply(rt, p["moe"], h, cfg.moe)
-    elif cfg.act == "swiglu":
-        m = L.swiglu(rt, p["mlp"], h)
-    else:
-        m = L.gelu_mlp(rt, p["mlp"], h)
+    with jax.named_scope("mlp"):
+        if cfg.moe is not None:
+            m, aux = moe_apply(rt, p["moe"], h, cfg.moe)
+        elif cfg.act == "swiglu":
+            m = L.swiglu(rt, p["mlp"], h)
+        else:
+            m = L.gelu_mlp(rt, p["mlp"], h)
     x = x + m
     x = rt.shard(x, "batch", "sp", None)
     return x, new_cache, aux
@@ -195,14 +197,15 @@ def forward(
         return (h, aux + a), None
 
     carry = (x.astype(cfg.dtype), jnp.zeros((), jnp.float32))
-    if cfg.unroll:
-        rb = _remat(cfg, body)
-        for i in range(cfg.n_layers):
-            lp = jax.tree.map(lambda t: t[i], params["blocks"])
-            carry, _ = rb(carry, lp)
-        x, aux = carry
-    else:
-        (x, aux), _ = jax.lax.scan(_remat(cfg, body), carry, params["blocks"])
+    with jax.named_scope("layer_stack"):
+        if cfg.unroll:
+            rb = _remat(cfg, body)
+            for i in range(cfg.n_layers):
+                lp = jax.tree.map(lambda t: t[i], params["blocks"])
+                carry, _ = rb(carry, lp)
+            x, aux = carry
+        else:
+            (x, aux), _ = jax.lax.scan(_remat(cfg, body), carry, params["blocks"])
     x = _apply_norm(cfg, params["final_norm"], x)
     logits = L.unembed(rt, params["embed"], x)
     if prefix:
@@ -259,7 +262,8 @@ def prefill(
         )
         return h, new_cache
 
-    x, (ck, cv) = _scan_or_unroll(cfg, body, x.astype(cfg.dtype), (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("layer_stack"):
+        x, (ck, cv) = _scan_or_unroll(cfg, body, x.astype(cfg.dtype), (params["blocks"], cache["k"], cache["v"]))
     x = _apply_norm(cfg, params["final_norm"], x)
     logits = L.unembed(rt, params["embed"], x[:, -1:])
     return logits, {"k": ck, "v": cv}
@@ -287,7 +291,8 @@ def decode_step(
         )
         return h, new_cache
 
-    x, (ck, cv) = _scan_or_unroll(cfg, body, x.astype(cfg.dtype), (params["blocks"], cache["k"], cache["v"]))
+    with jax.named_scope("layer_stack"):
+        x, (ck, cv) = _scan_or_unroll(cfg, body, x.astype(cfg.dtype), (params["blocks"], cache["k"], cache["v"]))
     x = _apply_norm(cfg, params["final_norm"], x)
     logits = L.unembed(rt, params["embed"], x)
     return logits, {"k": ck, "v": cv}

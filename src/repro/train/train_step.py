@@ -59,8 +59,9 @@ def make_train_step(
 
     def train_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        grads, _ = compress_grads(compression, grads)
-        new_params, new_opt, metrics = adamw.apply(opt_cfg, params, grads, opt_state)
+        with jax.named_scope("optimizer"):
+            grads, _ = compress_grads(compression, grads)
+            new_params, new_opt, metrics = adamw.apply(opt_cfg, params, grads, opt_state)
         metrics["loss"] = loss
         return new_params, new_opt, metrics
 
