@@ -251,8 +251,15 @@ def attention(
     kv_cache: tuple[jax.Array, jax.Array] | None = None,  # (B,Smax,K,Dh) x2
     cache_pos: jax.Array | None = None,  # scalar write offset (decode)
     kv_override: jax.Array | None = None,  # encoder states for cross-attn
+    cache_layer: jax.Array | None = None,  # layer index into a stacked cache
 ) -> tuple[jax.Array, tuple[jax.Array, jax.Array] | None]:
-    """Full attention layer.  Returns (out, updated_cache)."""
+    """Full attention layer.  Returns (out, updated_cache).
+
+    With ``cache_layer`` the cache is the whole stacked (L, B, Smax, K, Dh)
+    pair: the new rows are written at ``(cache_layer, 0, cache_pos, 0, 0)``,
+    that layer is read back for attention, and the stacked pair is returned,
+    so a layer scan can carry the cache and update it in place.
+    """
     B, S, D = x.shape
     N, K, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     G = N // K
@@ -277,15 +284,23 @@ def attention(
     if kv_cache is not None:
         ck, cv = kv_cache
         if cache_pos is not None:
+            at = (0, cache_pos, 0, 0)
+            if cache_layer is not None:
+                k, v, at = k[None], v[None], (cache_layer, *at)
             with jax.named_scope("kv_cache"):
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (0, cache_pos, 0, 0)
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (0, cache_pos, 0, 0)
-                )
-        k, v = ck, cv
+                ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), at)
+                cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), at)
         new_cache = (ck, cv)
+        if cache_layer is not None:
+            # the barrier keeps the attention dots' operand layout from
+            # spreading back into the stacked cache: the TPU compiler would
+            # otherwise lay the whole carried cache out for the dots and
+            # transpose all of it on the way into and out of the loop
+            ck, cv = jax.lax.optimization_barrier((
+                jax.lax.dynamic_index_in_dim(ck, cache_layer, keepdims=False),
+                jax.lax.dynamic_index_in_dim(cv, cache_layer, keepdims=False),
+            ))
+        k, v = ck, cv
         k_pos = jnp.arange(k.shape[1])
         k = rt.shard(k, "batch", "cache_seq", None, None)
         v = rt.shard(v, "batch", "cache_seq", None, None)

@@ -125,10 +125,12 @@ def _block(
     cache: tuple[jax.Array, jax.Array] | None = None,
     cache_pos: jax.Array | None = None,
     prefix: int = 0,
+    cache_layer: jax.Array | None = None,
 ):
     h = _apply_norm(cfg, p["ln1"], x)
     a, new_cache = L.attention(
-        rt, p["attn"], h, cfg.attn(prefix), positions, cache, cache_pos
+        rt, p["attn"], h, cfg.attn(prefix), positions, cache, cache_pos,
+        cache_layer=cache_layer,
     )
     x = x + a
     h = _apply_norm(cfg, p["ln2"], x)
@@ -277,22 +279,32 @@ def decode_step(
     cache: dict,
     pos: jax.Array,             # scalar int32: current write position
 ) -> tuple[jax.Array, dict]:
-    """One autoregressive step against a populated cache."""
+    """One autoregressive step against a populated cache.
+
+    The stacked cache rides in the layer scan's carry and each layer writes
+    only its new row into it, so a donated cache is updated in place:
+    scanned as ``xs``/``ys`` it would be restacked into new buffers and
+    copied whole every step.
+    """
     params = cast_floats(params, cfg.dtype)
     x = L.embed(rt, params["embed"], tokens)
     if cfg.embed_scale:
         x = x * math.sqrt(cfg.d_model)
     positions = pos[None] if pos.ndim == 0 else pos
 
-    def body(h, xs):
-        lp, ck, cv = xs
-        h, new_cache, _ = _block(
-            rt, cfg, lp, h, positions, cache=(ck, cv), cache_pos=pos
+    def body(carry, xs):
+        h, kv = carry
+        lp, layer = xs
+        h, kv, _ = _block(
+            rt, cfg, lp, h, positions, cache=kv, cache_pos=pos, cache_layer=layer
         )
-        return h, new_cache
+        return (h, kv), None
 
+    layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
     with jax.named_scope("layer_stack"):
-        x, (ck, cv) = _scan_or_unroll(cfg, body, x.astype(cfg.dtype), (params["blocks"], cache["k"], cache["v"]))
+        (x, (ck, cv)), _ = _scan_or_unroll(
+            cfg, body, (x.astype(cfg.dtype), (cache["k"], cache["v"])), (params["blocks"], layers)
+        )
     x = _apply_norm(cfg, params["final_norm"], x)
     logits = L.unembed(rt, params["embed"], x)
     return logits, {"k": ck, "v": cv}

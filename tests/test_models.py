@@ -2,6 +2,9 @@
 one decode step on CPU, asserting shapes and finiteness (harness deliverable
 f), plus model-level invariants (causality, prefill/decode consistency)."""
 
+import re
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -119,6 +122,72 @@ class TestInvariants:
             np.asarray(lg_full[:, -1], np.float32),
             atol=3e-2,  # bf16 cache
         )
+
+    def test_decode_steps_match_forward(self):
+        """three donated decode steps after prefill each match forward over
+        the same prefix, and the unrolled layer loop gives the scan's step"""
+        h = load("granite_8b", smoke=True)
+        params = tree_init(h.param_specs(), KEY)
+        from repro.models import transformer
+
+        S, steps = 8, 3
+        tokens = jnp.asarray(
+            np.random.default_rng(4).integers(0, 64, (2, S + steps)), jnp.int32
+        )
+        cell = ShapeCell("t", "decode", S + steps + 2, 2)
+        cache = tree_init(h.serve_state_specs(cell), KEY)
+        _, cache = transformer.prefill(RT, h.cfg, params, tokens[:, :S], cache)
+        step = jax.jit(partial(transformer.decode_step, RT, h.cfg), donate_argnums=(2,))
+        unrolled = jax.jit(partial(transformer.decode_step, RT, h.clone(unroll=True).cfg))
+        for i in range(steps):
+            tok, pos = tokens[:, S + i : S + i + 1], jnp.asarray(S + i, jnp.int32)
+            out_unrolled = unrolled(params, tok, cache, pos)
+            lg, cache = step(params, tok, cache, pos)
+            lg_full, _ = transformer.forward(RT, h.cfg, params, tokens[:, : S + i + 1])
+            # bf16 cache; the two loops fuse, and so round, differently
+            for got, want in zip(
+                jax.tree.leaves((lg[:, -1], out_unrolled)),
+                jax.tree.leaves((lg_full[:, -1], (lg, cache))),
+            ):
+                np.testing.assert_allclose(
+                    np.asarray(got, np.float32), np.asarray(want, np.float32), atol=3e-2
+                )
+
+    def test_decode_carries_cache_in_place(self):
+        """the donated stacked cache goes through the layer loop as one
+        carried pair and comes back in the donated buffers, with no copy of
+        it round the loop and no restacked copy of it beside it
+
+        Only the entry computation is checked here: the CPU backend performs
+        a bfloat16 dynamic-update-slice in float32 over the whole buffer, so
+        inside the loop it copies what the TPU updates in place
+        (test_tpu_compile.py checks the whole decode program for the TPU)."""
+        h = load("granite_3_2b", smoke=True)
+        params = jax.eval_shape(lambda: tree_init(h.param_specs(), KEY))
+        cache = jax.eval_shape(
+            lambda: tree_init(h.serve_state_specs(ShapeCell("t", "decode", 40, 2)), KEY)
+        )
+        args = (params, cache, jax.ShapeDtypeStruct((2, 1), jnp.int32),
+                jax.ShapeDtypeStruct((), jnp.int32))
+        hlo = jax.jit(h.decode(RT), donate_argnums=(1,)).lower(*args).compile().as_text()
+        stacked = re.compile(
+            r"\b[a-z]+[0-9]*\[%s\]" % ",".join(map(str, cache["k"].shape))
+        )
+        lines = hlo.splitlines()
+        start = next(i for i, line in enumerate(lines) if line.startswith("ENTRY "))
+        entry = lines[start + 1 : lines.index("}", start)]
+        made = [line.split(" = ", 1)[1] for line in entry if " = " in line]
+        made = [m for m in made if stacked.match(m)]
+        assert made and all(
+            re.search(r"\} (parameter|get-tuple-element)\(", m) for m in made
+        ), made
+        loop = next(m for m in (line.split(" = ", 1)[-1] for line in entry)
+                    if re.search(r"\) while\(", m))
+        assert len(stacked.findall(loop.split(" while(")[0])) == 2
+        n = len(jax.tree.leaves(params))
+        alias = re.search(r"input_output_alias=\{(.*?)\s\}", hlo).group(1)
+        pairs = dict(re.findall(r"\{(\d+)\}: \((\d+),", alias))
+        assert pairs == {"1": str(n), "2": str(n + 1)}
 
     def test_rwkv_decode_matches_forward(self):
         h = load("rwkv6_1_6b", smoke=True)

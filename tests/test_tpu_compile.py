@@ -10,6 +10,8 @@ one process at a time may load the TPU library, and every pytest worker
 imports every test file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -106,6 +108,32 @@ def test_decode_step_compiles_at_published_widths(one_chip, granite):
         ))
     ).compile()
     assert 0 < _bytes(compiled) < 16e9
+
+
+@pytest.mark.parametrize("arch", ["granite-3-2b", "granite-8b"])
+def test_donated_decode_updates_cache_in_place(one_chip, arch):
+    """The carried cache stays in the donated buffers: no copy of a whole
+    stacked cache anywhere in the program, and no scratch buffer as large as
+    one.  granite-3-2b's 64-wide heads give the cache a transposed device
+    layout, granite-8b's 128-wide heads the row-major one."""
+    h = load(arch).clone(n_layers=2)
+    cache = tree_abstract(h.serve_state_specs(ShapeCell("decode", "decode", 1024, 8)))
+    compiled = jax.jit(h.decode(Runtime(rules=None)), donate_argnums=(1,)).lower(
+        *_on(one_chip, (
+            tree_abstract(h.param_specs(), dtype=jnp.bfloat16),
+            cache,
+            jax.ShapeDtypeStruct((8, 1), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        ))
+    ).compile()
+    shape = "[%s]" % ",".join(map(str, cache["k"].shape))
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= \w+%s\{[^}]*\} copy(-start)?\(" % re.escape(shape), line)]
+    assert not copies, copies
+    m = compiled.memory_analysis()
+    cache_bytes = cache["k"].size * cache["k"].dtype.itemsize
+    assert m.temp_size_in_bytes < cache_bytes
+    assert m.alias_size_in_bytes >= 2 * cache_bytes
 
 
 def test_train_step_compiles_at_published_widths(single_train):
