@@ -13,9 +13,9 @@ Default phases, in one process on one chip:
 * train: the same widths cut to 4 layers, 5 AdamW steps at 8 x 2048
   through ``launch.train.train``; every loss must be finite.
 
-``--four-chips`` runs one train step of the 4-layer model through
-``train.train_step.build_train_step`` on a 2x2 ("data", "model") mesh and
-the same step on one device, and compares them.
+``--four-chips`` runs one train step of the 4-layer model on a 2x2
+("data", "model") mesh and the same step on one device, both built by
+``launch.train.setup_train``, and compares them.
 
 Times and memory printed here are smoke numbers, not benchmark numbers.
 Any failed check exits non-zero; the last line of stdout is the JSON
@@ -147,25 +147,21 @@ def train_phase(harness, *, steps: int, batch: int, seq: int, seed: int) -> None
 
 def four_chip_phase(harness, devices, *, batch: int, seq: int, seed: int) -> None:
     """One train step on a 2x2 mesh of ``devices`` against the same step
-    on ``devices[0]``, from the same params and batch."""
+    on ``devices[0]``, from the same seed and batch, both built by the
+    launcher (``launch.train.setup_train``) as ``launch.train.train`` does."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from repro.data.pipeline import DataConfig, SyntheticSource
     from repro.launch.hlo_stats import collective_stats
     from repro.launch.mesh import make_smoke_mesh
-    from repro.models.api import ShapeCell
-    from repro.models.layers import Runtime
-    from repro.models.param import tree_init
+    from repro.launch.train import setup_train
     from repro.optim import adamw
     from repro.optim.compression import CompressionConfig
     from repro.parallel.sharding import dp_size
-    from repro.train.train_step import build_train_step, lower_bundle, make_train_step
 
     cfg = harness.cfg
     mesh = make_smoke_mesh(2, 2, devices=devices)
-    cell = ShapeCell("smoke_train", "train", seq, batch)
     opt_cfg = adamw.OptConfig(warmup_steps=10, decay_steps=100)
     print(f"[smoke] four-chip {cfg.name}: layers={cfg.n_layers} batch={batch} "
           f"seq={seq} mesh={dict(mesh.shape)} dp={dp_size(mesh.shape)}", flush=True)
@@ -173,60 +169,43 @@ def four_chip_phase(harness, devices, *, batch: int, seq: int, seed: int) -> Non
     raw = SyntheticSource(DataConfig(batch, seq, cfg.vocab_size, seed=seed)).batch_at(0)
     host_batch = {"tokens": raw[:, :-1], "labels": raw[:, 1:]}
 
-    def fresh_state(device):
-        with jax.default_device(device):
-            params = tree_init(harness.param_specs(), jax.random.PRNGKey(seed),
-                               dtype=jnp.bfloat16)
-            return params, adamw.init_opt_state(params)
+    def build(mesh):
+        return setup_train(harness, mesh, batch=batch, seq=seq, opt_cfg=opt_cfg,
+                           compression=CompressionConfig(), seed=seed)
 
-    def summary(params, metrics):
-        pnorm = float(adamw.global_norm(params))
-        return float(metrics["loss"]), float(metrics["grad_norm"]), pnorm
+    def step(built):
+        """(loss, gradient norm, parameter norm) of one step, and its time."""
+        batch = built.feed(host_batch)
+        t0 = time.perf_counter()
+        params, _, metrics = jax.block_until_ready(
+            built.step(built.params, built.opt_state, batch))
+        step_s = time.perf_counter() - t0
+        pnorm = float(jax.jit(adamw.global_norm)(params))
+        return (float(metrics["loss"]), float(metrics["grad_norm"]), pnorm), step_s
 
     # sharded step
-    bundle = build_train_step(harness, cell, mesh, opt_cfg=opt_cfg)
-    t0 = time.perf_counter()
-    sharded = lower_bundle(bundle, mesh).compile()
-    compile_s = time.perf_counter() - t0
-    hlo = sharded.as_text()
-    stats = collective_stats(hlo)
-    print(f"[smoke] four-chip compile_s={compile_s:.3f} collectives="
+    sharded = build(mesh)
+    stats = collective_stats(sharded.step.as_text())
+    print(f"[smoke] four-chip compile_s={sharded.compile_s:.3f} collectives="
           f"{stats.count} by_kind="
           f"{ {k: int(v) for k, v in sorted(stats.by_kind.items())} }", flush=True)
     check(stats.count > 0, "no collectives in the sharded step")
 
-    params, opt = fresh_state(devices[0])
-    args = jax.device_put((params, opt, host_batch), bundle.in_shardings)
-    del params, opt
-    spread = {d for leaf in jax.tree.leaves(args[:2]) for d in leaf.sharding.device_set}
-    split = sum(
-        leaf.addressable_shards[0].data.shape != leaf.shape
-        for leaf in jax.tree.leaves(args[:2])
-    )
-    n_leaves = len(jax.tree.leaves(args[:2]))
+    state = jax.tree.leaves((sharded.params, sharded.opt_state))
+    spread = {d for leaf in state for d in leaf.sharding.device_set}
+    split = sum(leaf.addressable_shards[0].data.shape != leaf.shape for leaf in state)
     print(f"[smoke] four-chip placement: devices={len(spread)} "
-          f"split_leaves={split}/{n_leaves}", flush=True)
+          f"split_leaves={split}/{len(state)}", flush=True)
     check(spread == set(devices), "state is not on all four devices")
     check(split > 0, "no parameter or optimizer leaf is split across devices")
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(sharded(*args))
-    step_s = time.perf_counter() - t0
-    got = summary(out[0], out[2])
-    del args, out
+    del state
+    got, step_s = step(sharded)
+    del sharded
     for d in devices:
         print(f"[smoke] four-chip {d}: {memory_line(d)}", flush=True)
 
     # the same step on one device
-    params, opt = fresh_state(devices[0])
-    single = jax.jit(
-        make_train_step(harness.loss(Runtime(rules=None)), opt_cfg,
-                        CompressionConfig()),
-        donate_argnums=(0, 1),
-    )
-    batch1 = jax.device_put(host_batch, devices[0])
-    out = jax.block_until_ready(single(params, opt, batch1))
-    want = summary(out[0], out[2])
-    del params, opt, out
+    want, _ = step(build(make_smoke_mesh(1, 1, devices=devices[:1])))
 
     print(f"[smoke] four-chip sharded step_s={step_s:.4f} (smoke number, first call)",
           flush=True)

@@ -35,6 +35,7 @@ class LMConfig:
     d_ff: int
     vocab_size: int
     norm: str = "rms"              # rms | ln
+    rms_eps: float = 1e-6          # RMSNorm epsilon
     act: str = "swiglu"            # swiglu | gelu
     window: int | None = None      # sliding-window attention
     rope_theta: float = 10000.0
@@ -85,7 +86,7 @@ def _norm_specs(cfg: LMConfig) -> Any:
 
 def _apply_norm(cfg: LMConfig, p: Any, x: jax.Array) -> jax.Array:
     with jax.named_scope("norm"):
-        return L.rmsnorm(p, x) if cfg.norm == "rms" else L.layernorm(p, x)
+        return L.rmsnorm(p, x, cfg.rms_eps) if cfg.norm == "rms" else L.layernorm(p, x)
 
 
 def block_specs(cfg: LMConfig) -> dict:

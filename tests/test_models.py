@@ -99,6 +99,19 @@ class TestInvariants:
             np.asarray(lg1[:, 12:], np.float32), np.asarray(lg2[:, 12:], np.float32)
         )
 
+    def test_rms_eps_reaches_the_norms(self):
+        """the config's RMSNorm epsilon is the one the forward uses"""
+        h = load("granite_8b", smoke=True)
+        params = tree_init(h.param_specs(), KEY)
+        from repro.models import transformer
+
+        tokens = jnp.asarray(np.random.default_rng(2).integers(0, 64, (1, 16)), jnp.int32)
+        base, _ = transformer.forward(RT, h.cfg, params, tokens)
+        same, _ = transformer.forward(RT, h.clone(rms_eps=1e-6).cfg, params, tokens)
+        wide, _ = transformer.forward(RT, h.clone(rms_eps=1.0).cfg, params, tokens)
+        np.testing.assert_array_equal(np.asarray(base), np.asarray(same))
+        assert not np.allclose(np.asarray(base, np.float32), np.asarray(wide, np.float32))
+
     def test_prefill_decode_consistency(self):
         """prefill(S tokens) then decode == prefill(S+1 tokens) logits"""
         h = load("granite_8b", smoke=True)

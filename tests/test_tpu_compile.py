@@ -14,6 +14,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -149,3 +150,49 @@ def test_sharded_train_step_spreads_over_2x2(topo, granite, single_train):
     assert stats.by_kind.get("all-reduce", 0) > 0     # gradient reduction
     assert stats.by_kind.get("all-gather", 0) > 0     # model-sharded weights
     assert _bytes(compiled) < _bytes(single_train) / 2
+
+
+def _replica_groups(line: str) -> set:
+    """The replica groups of one collective's HLO line, as sets of
+    partition ids: ``{{0,2},{1,3}}`` or the iota form ``[2,2]<=[2,2]T(1,0)``
+    (ids 0..n-1 laid out as the dims, transposed, cut into groups)."""
+    m = re.search(r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", line)
+    if m:
+        dims = [int(x) for x in m[3].split(",")]
+        ids = np.arange(int(np.prod(dims))).reshape(dims)
+        if m[4]:
+            ids = ids.transpose([int(x) for x in m[4].split(",")])
+        return {frozenset(g) for g in ids.reshape(int(m[1]), int(m[2])).tolist()}
+    m = re.search(r"replica_groups=\{((?:\{[\d,]*\},?)*)\}", line)
+    if m:
+        return {frozenset(int(x) for x in g.split(",") if x)
+                for g in re.findall(r"\{([\d,]*)\}", m[1])}
+    return set()
+
+
+def test_replica_groups_read_both_forms():
+    assert _replica_groups("x = all-gather(y), replica_groups=[2,2]<=[4], dim") == {
+        frozenset({0, 1}), frozenset({2, 3})}
+    assert _replica_groups("replica_groups=[2,2]<=[2,2]T(1,0), to_apply") == {
+        frozenset({0, 2}), frozenset({1, 3})}
+    assert _replica_groups("replica_groups={{0,2},{1,3}}, to_apply") == {
+        frozenset({0, 2}), frozenset({1, 3})}
+
+
+def test_granite_8b_l8_train_step_fits_a_2x2_host(topo):
+    """The benchmark's four-chip cell: granite-8b at 8 layers and published
+    widths and RMSNorm epsilon, global batch 8 x 2048, on a ("data", "model")
+    2x2 mesh.  It fits each chip, and its gradients and weights move over
+    both axes."""
+    h = load("granite-8b").clone(n_layers=8, rms_eps=1e-5)
+    mesh = make_smoke_mesh(2, 2, devices=topo.devices)
+    compiled = lower_bundle(build_train_step(h, ShapeCell("train", "train", 2048, 8), mesh,
+                                             opt_cfg=OPT), mesh).compile()
+    assert _bytes(compiled) < 16e9
+    # partition ids follow the mesh's device order, data-major
+    ids = np.arange(4).reshape(2, 2)
+    over_model = {frozenset(row) for row in ids.tolist()}
+    over_data = {frozenset(col) for col in ids.T.tolist()}
+    groups = [_replica_groups(line) for line in compiled.as_text().splitlines()
+              if re.search(r" (all-gather|reduce-scatter|all-reduce)(-start)?\(", line)]
+    assert over_model in groups and over_data in groups
